@@ -97,6 +97,8 @@ def _parse_args(argv):
 
 def main(argv=None) -> int:
     args = _parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.scale:
         # must land before benchmarks.common is imported (it reads the
         # env at import time to size the datasets)

@@ -22,7 +22,6 @@ from repro.core.index import SSHIndex
 from repro.core.rerank import SearchStats
 from repro.db.config import SearchConfig, config_from_legacy_kwargs
 from repro.kernels import ops
-from repro.kernels import ref as kref
 
 
 @dataclasses.dataclass
@@ -213,12 +212,14 @@ def brute_force_topk(query: jnp.ndarray, series: jnp.ndarray, topk: int,
                      band: Optional[int] = None):
     """Gold standard (paper §5.3): exact DTW over the whole database.
 
-    Routed through the shared jnp reference (``kernels.ref``) so the
-    gold distances use the same arithmetic — including the banded
-    window-DP's summation order — as the production re-rank, keeping
-    exactness tests ulp-comparable.
+    Routed through the same DTW dispatch as the production re-rank
+    (``ops.dtw_rerank``, backend ``"auto"``) so the gold distances use
+    the same arithmetic — the jnp reference's banded window DP off-TPU,
+    keeping exactness tests ulp-comparable, and the wavefront kernel on
+    a TPU, where the window DP's (N, 2·band+1) working arrays run about a
+    hundred times slower.
     """
-    d = kref.dtw_wavefront_ref(query, series, band=band)
+    d = ops.dtw_rerank(query, series, band)
     vals, idx = jax.lax.top_k(-d, topk)
     return np.asarray(idx), np.asarray(-vals)
 

@@ -23,21 +23,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core.dtw import BIG
 from repro.core.index import SSHParams
 from repro.db.config import SearchConfig, config_from_legacy_kwargs
 
-try:                        # jax >= 0.6: public API, replication kw check_vma
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-except AttributeError:      # jax 0.4.x: experimental module, kw check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
 def shard_map_nocheck(f, mesh: Mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across jax versions."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: False})
+    """shard_map with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _signature(series: jnp.ndarray, filters: jnp.ndarray, cws: dict,
@@ -83,7 +76,7 @@ def _make_query_core(encode, mesh: Mesh, config: SearchConfig):
         from repro.kernels import ops
         sig = encode(q, state)                                # (K,)
         coll = jnp.sum((sigs == sig[None, :]).astype(jnp.int32), axis=-1)
-        _, cand = jax.lax.top_k(coll, local_c)                # local ids
+        hits, cand = jax.lax.top_k(coll, local_c)             # local ids
         cand_series = jnp.take(series, cand, axis=0)
         thr = None
         if abandon:
@@ -92,12 +85,17 @@ def _make_query_core(encode, mesh: Mesh, config: SearchConfig):
             # top-k is <= this bound, and the global k-th is <= every
             # shard's local k-th, so abandoned lanes (exact > thr) can
             # never reach the gathered global top-k — results identical.
+            # A shard with fewer than topk hits has no such bound (inf).
             seed = ops.dtw_rerank(q, cand_series[:topk], band,
                                   use_pallas=ops.resolve_backend(backend))
-            thr = jnp.sort(seed)[topk - 1]
+            thr = jnp.where(hits[topk - 1] > 0, jnp.sort(seed)[topk - 1],
+                            jnp.inf)
         d = ops.dtw_rerank(q, cand_series, band,
                            use_pallas=ops.resolve_backend(backend),
                            threshold=thr)
+        # only rows that collide in at least one hash are candidates, as
+        # in the batched and sequential probes; the rest rank as filler
+        d = jnp.where(hits > 0, d, BIG)
 
         shard_id = jax.lax.axis_index(axes)
         n_local = series.shape[0]

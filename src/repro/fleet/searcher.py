@@ -42,6 +42,7 @@ from typing import Dict, List, Optional, Tuple, Union
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.dtw import BIG
 from repro.core.rerank import SearchStats
 from repro.db.config import SearchConfig
 from repro.distributed.fault_tolerance import StragglerPolicy
@@ -261,7 +262,10 @@ class FleetSearcher:
                                 for s in range(self.n_shards)])
         k = min(cfg.topk, all_d.shape[0])
         order = np.argsort(all_d, kind="stable")[:k]
-        return all_i[order], all_d[order], hedged, failovers, degraded
+        # filler slots (fewer colliding rows than topk) carry id -1, as
+        # the batched path's do, so per_query trims them
+        ids = np.where(all_d[order] < BIG * 0.5, all_i[order], -1)
+        return ids, all_d[order], hedged, failovers, degraded
 
     def search_batch(self, queries: jnp.ndarray):
         from repro.bench.timing import StageTimer

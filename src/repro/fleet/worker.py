@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.dtw import BIG
+
 
 @dataclasses.dataclass
 class ShardReplica:
@@ -85,10 +87,10 @@ class FleetWorker:
         c = min(local_c, rep.n_rows)
         coll = jnp.sum((rep.signatures == sig[None, :]).astype(jnp.int32),
                        axis=-1)
-        _, cand = jax.lax.top_k(coll, c)
+        hits, cand = jax.lax.top_k(coll, c)
         cand_series = jnp.take(rep.series, cand, axis=0)
         thr = None
-        if abandon and c > topk:
+        if abandon and c > topk and int(hits[topk - 1]) > 0:
             # shard-local seed threshold (same soundness argument as the
             # shard_map path): the global k-th best is <= this shard's
             # k-th best over its first topk hash hits, so a lane the
@@ -98,5 +100,7 @@ class FleetWorker:
             thr = jnp.sort(seed)[topk - 1]
         d = ops.dtw_rerank(q, cand_series, band, use_pallas=use_pallas,
                            threshold=thr)
+        # rows colliding in no hash are not candidates (as in dist_index)
+        d = jnp.where(hits > 0, d, BIG)
         gids = np.asarray(cand, np.int64) + rep.row_start
         return gids, np.asarray(d, np.float32)

@@ -14,9 +14,12 @@ Grid: (N / 128,).
 
 ``collision_count_batch`` is the fused batched-probe variant for the
 serving engine (DESIGN.md §4): B query signatures against the same
-database in one kernel.  Grid: (N / 128, B) with queries innermost, so a
-database block stays VMEM-resident while every query row scans it — the
-database streams from HBM once per batch instead of once per query.
+database in one kernel.  Grid: (N / 128,); each step loads one database
+block and counts it against every query of the batch, so the database
+streams from HBM once per batch instead of once per query.  The queries
+arrive as a resident (L, B_pad, 1) block — one (B_pad, 1) key column per
+table — and the count accumulates on a (B_pad, 128) tile, table by
+table, so every block obeys the TPU's (8, 128) tiling.
 """
 from __future__ import annotations
 
@@ -64,34 +67,42 @@ def collision_count(query_keys: jnp.ndarray, db_keys: jnp.ndarray,
     return out[0, :n]
 
 
+def _batch_kernel(q_ref, db_ref, o_ref):
+    db = db_ref[...]                                 # (L, LANES)
+    acc = jnp.zeros(o_ref.shape, jnp.int32)          # (B_pad, LANES)
+    for k in range(db.shape[0]):                     # static, per table
+        acc = acc + (db[k:k + 1, :] == q_ref[k]).astype(jnp.int32)
+    o_ref[...] = acc
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def collision_count_batch(query_keys: jnp.ndarray, db_keys: jnp.ndarray,
                           interpret: bool = False) -> jnp.ndarray:
     """queries (B, L), db (N, L) int32 -> (B, N) int32 match counts.
 
-    Same block layout as ``collision_count`` (keys on sublanes, candidates
-    on lanes); the batch adds a second grid axis that walks query columns
-    of the transposed (K_pad, B) query matrix.
+    Keys on sublanes and candidates on lanes, as in ``collision_count``;
+    the batch rides the output's sublanes, padded to a multiple of 8
+    (padding query rows are sliced off).
     """
     b, k = query_keys.shape
     n, k2 = db_keys.shape
     assert k == k2, "query/db key widths must match"
-    kp = (-k) % 8
+    bp = (-b) % 8
     np_ = (-n) % LANES
-    db = jnp.pad(db_keys.astype(jnp.int32).T, ((0, kp), (0, np_)),
-                 constant_values=_DB_SENTINEL)          # (K_pad, N_pad)
-    q = jnp.pad(query_keys.astype(jnp.int32).T, ((0, kp), (0, 0)),
-                constant_values=_Q_SENTINEL)            # (K_pad, B)
+    db = jnp.pad(db_keys.astype(jnp.int32).T, ((0, 0), (0, np_)),
+                 constant_values=_DB_SENTINEL)          # (L, N_pad)
+    q = jnp.pad(query_keys.astype(jnp.int32).T, ((0, 0), (0, bp)),
+                constant_values=_Q_SENTINEL)[:, :, None]  # (L, B_pad, 1)
 
     out = pl.pallas_call(
-        _kernel,
-        out_shape=jax.ShapeDtypeStruct((b, n + np_), jnp.int32),
-        grid=((n + np_) // LANES, b),     # queries innermost: db block reused
+        _batch_kernel,
+        out_shape=jax.ShapeDtypeStruct((b + bp, n + np_), jnp.int32),
+        grid=((n + np_) // LANES,),
         in_specs=[
-            pl.BlockSpec((k + kp, 1), lambda g, i: (0, i)),
-            pl.BlockSpec((k + kp, LANES), lambda g, i: (0, g)),
+            pl.BlockSpec((k, b + bp, 1), lambda g: (0, 0, 0)),
+            pl.BlockSpec((k, LANES), lambda g: (0, g)),
         ],
-        out_specs=pl.BlockSpec((1, LANES), lambda g, i: (i, g)),
+        out_specs=pl.BlockSpec((b + bp, LANES), lambda g: (0, g)),
         interpret=interpret,
     )(q, db)
-    return out[:, :n]
+    return out[:b, :n]

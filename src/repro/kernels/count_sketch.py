@@ -15,11 +15,13 @@ are pre-hashed buckets (B, R, S) int32 with −1 for padding/masked
 shingles and their signs (B, R, S) f32 with 0 at the same slots — the
 one-hot compare drops −1 for free since lane indices are non-negative.
 
-Grid: (B, R, S_pad / CHUNK), chunks innermost so each (1, 1, width)
-output block stays VMEM-resident while its shingle stream walks through;
-``@pl.when(step == 0)`` zero-initialises per (b, r).  VMEM: the one-hot
-block at the default width 4096 is (128, 4096) f32 = 2 MiB — comfortable
-against the ~16 MiB budget.
+Grid: (B, S_pad / CHUNK), chunks innermost so each (1, R, width) output
+block stays VMEM-resident while its shingle stream walks through;
+``@pl.when(step == 0)`` zero-initialises per b.  The bucket/sign blocks
+are (1, R_pad, CHUNK) with R padded to 8 sublanes; the kernel transposes
+each to (CHUNK, R_pad) to get one bucket column per table row.  VMEM:
+the one-hot block at the default width 4096 is (128, 4096) f32 = 2 MiB
+— comfortable against the ~16 MiB budget.
 """
 from __future__ import annotations
 
@@ -33,18 +35,20 @@ CHUNK = 128
 
 
 def _kernel(b_ref, s_ref, o_ref):
-    step = pl.program_id(2)
+    step = pl.program_id(1)
 
     @pl.when(step == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    width = o_ref.shape[-1]
-    bkt = b_ref[...].reshape(CHUNK, 1)               # (CHUNK, 1) int32
-    sgn = s_ref[...].reshape(CHUNK, 1)               # (CHUNK, 1) f32
+    _, rows, width = o_ref.shape
+    bkt = b_ref[0].T                                 # (CHUNK, R_pad) int32
+    sgn = s_ref[0].T                                 # (CHUNK, R_pad) f32
     lanes = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, width), 1)
-    hits = jnp.where(lanes == bkt, sgn, 0.0)         # one-hot ±1
-    o_ref[...] += jnp.sum(hits, axis=0).reshape(1, 1, width)
+    for r in range(rows):                            # static, per table row
+        hits = jnp.where(lanes == bkt[:, r:r + 1],
+                         sgn[:, r:r + 1], 0.0)       # one-hot ±1
+        o_ref[0, r:r + 1, :] += jnp.sum(hits, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("width", "interpret"))
@@ -53,19 +57,18 @@ def cs_tables(bucket: jnp.ndarray, sign: jnp.ndarray, width: int,
     """bucket (B, R, S) int32 (−1 invalid), sign (B, R, S) f32 (0 at −1)
     -> (B, R, width) f32 signed count-sketch tables."""
     b, r, s = bucket.shape
+    rp = (-r) % 8
     sp = (-s) % CHUNK
-    bkt = jnp.pad(bucket.astype(jnp.int32), ((0, 0), (0, 0), (0, sp)),
+    bkt = jnp.pad(bucket.astype(jnp.int32), ((0, 0), (0, rp), (0, sp)),
                   constant_values=-1)
-    sgn = jnp.pad(sign.astype(jnp.float32), ((0, 0), (0, 0), (0, sp)))
+    sgn = jnp.pad(sign.astype(jnp.float32), ((0, 0), (0, rp), (0, sp)))
 
+    in_spec = pl.BlockSpec((1, r + rp, CHUNK), lambda i, c: (i, 0, c))
     return pl.pallas_call(
         _kernel,
         out_shape=jax.ShapeDtypeStruct((b, r, width), jnp.float32),
-        grid=(b, r, (s + sp) // CHUNK),
-        in_specs=[
-            pl.BlockSpec((1, 1, CHUNK), lambda i, j, c: (i, j, c)),
-            pl.BlockSpec((1, 1, CHUNK), lambda i, j, c: (i, j, c)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, width), lambda i, j, c: (i, j, 0)),
+        grid=(b, (s + sp) // CHUNK),
+        in_specs=[in_spec, in_spec],
+        out_specs=pl.BlockSpec((1, r, width), lambda i, c: (i, 0, 0)),
         interpret=interpret,
     )(bkt, sgn)

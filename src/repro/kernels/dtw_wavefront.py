@@ -65,10 +65,9 @@ def _make_step(q_ref, x_ref, *, m: int, r: int, b_w: int, pad: int):
         i = offset + u                      # query index of cell u
         j = d - i                           # candidate index of cell u
         # q[i] for u ascending — contiguous slice of the padded query
-        q_vals = pl.load(q_ref, (pl.ds(offset + pad, b_w), slice(None)))
+        q_vals = q_ref[pl.ds(offset + pad, b_w), :]
         # x[j] = x_rev[m-1-j]; ascending in u — contiguous slice
-        x_vals = pl.load(x_ref, (pl.ds(m - 1 - d + offset + pad, b_w),
-                                 slice(None)))
+        x_vals = x_ref[pl.ds(m - 1 - d + offset + pad, b_w), :]
         cost = (q_vals - x_vals) ** 2       # (b_w, LANES)
 
         even = (d % 2) == 0
@@ -83,6 +82,20 @@ def _make_step(q_ref, x_ref, *, m: int, r: int, b_w: int, pad: int):
     return step
 
 
+def _big_tile(b_w: int) -> jnp.ndarray:
+    """A (b_w, LANES) tile of BIG for the loop carries' initial value.
+
+    Built from iotas over both axes rather than as a splat constant:
+    Mosaic lays a loop carry out like its initial value, a splat (or a
+    one-axis iota) gets a layout replicated along an axis, and the body's
+    per-cell result cannot be relaid into that, so the kernel would not
+    compile.
+    """
+    u = jax.lax.broadcasted_iota(jnp.int32, (b_w, LANES), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (b_w, LANES), 1)
+    return jnp.where(u + lane >= 0, BIG, 0.0).astype(jnp.float32)
+
+
 def _kernel(q_ref, x_ref, o_ref, *, m: int, r: int, b_w: int, pad: int):
     step = _make_step(q_ref, x_ref, m=m, r=r, b_w=b_w, pad=pad)
 
@@ -90,8 +103,7 @@ def _kernel(q_ref, x_ref, o_ref, *, m: int, r: int, b_w: int, pad: int):
         prev1, prev2 = carry
         return (step(d, prev1, prev2), prev1)
 
-    init = (jnp.full((b_w, LANES), BIG, jnp.float32),
-            jnp.full((b_w, LANES), BIG, jnp.float32))
+    init = (_big_tile(b_w), _big_tile(b_w))
     final1, _ = jax.lax.fori_loop(0, 2 * m - 1, body, init)
     o_ref[...] = final1[r, :][None, :]
 
@@ -125,8 +137,7 @@ def _kernel_thr(q_ref, x_ref, t_ref, o_ref, *, m: int, r: int, b_w: int,
         d, prev1, prev2 = carry
         return (d + 1, step(d, prev1, prev2), prev1)
 
-    init = (0, jnp.full((b_w, LANES), BIG, jnp.float32),
-            jnp.full((b_w, LANES), BIG, jnp.float32))
+    init = (0, _big_tile(b_w), _big_tile(b_w))
     _, final1, _ = jax.lax.while_loop(cond, body, init)
     # on early exit final1[r] is a mid-DP cell of a dead lane: >= the
     # lane's bound > thr, so the mask below sends it to BIG as required

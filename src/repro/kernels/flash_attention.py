@@ -34,12 +34,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, kv_block: int, t: int,
 
     def body(j, carry):
         m, l, acc = carry
-        # leading axis as a length-1 ds slice: bare int indices are
-        # rejected by the interpret-mode discharge rule on current JAX
-        k_blk = pl.load(k_ref, (pl.ds(0, 1), pl.ds(j * kv_block, kv_block),
-                                slice(None)))[0].astype(jnp.float32)
-        v_blk = pl.load(v_ref, (pl.ds(0, 1), pl.ds(j * kv_block, kv_block),
-                                slice(None)))[0].astype(jnp.float32)
+        k_blk = k_ref[0, pl.ds(j * kv_block, kv_block), :].astype(jnp.float32)
+        v_blk = v_ref[0, pl.ds(j * kv_block, kv_block), :].astype(jnp.float32)
         logits = q @ k_blk.T                          # (qb, kvb)
         if causal:
             q_pos = i * q_block + jax.lax.broadcasted_iota(

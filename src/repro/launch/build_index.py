@@ -34,6 +34,7 @@ from repro.data.timeseries import extract_subsequences, random_walk, \
     synthetic_ecg
 from repro.db import TimeSeriesDB
 from repro.encoders import IndexSpec, make_encoder
+from repro.launch.compile_cache import enable_compile_cache
 
 _GENERATORS = {"ecg": synthetic_ecg, "randomwalk": random_walk}
 
@@ -55,6 +56,7 @@ def main():
                     help="signature-build kernel backend (Pallas "
                          "sketch_conv vs jnp reference)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     stream = _GENERATORS[args.dataset](args.points, seed=3)
     series = extract_subsequences(stream, args.length, stride=1, znorm=True)

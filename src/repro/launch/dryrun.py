@@ -134,8 +134,6 @@ def run_cell(arch_name: str, shape: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # jax 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     # NOTE: XLA cost_analysis counts while bodies once and is per-device —
     # the executed_costs parser multiplies loop trip counts (validated
     # exact on hand-countable programs; see tests/test_hlo_graph.py).

@@ -8,6 +8,7 @@ sequential re-rank with ``--sequential``, or a saved database with
 hashing makes avoidable).
 
     PYTHONPATH=src python -m repro.launch.serve --arch ssh-ecg --requests 32
+    PYTHONPATH=src python -m repro.launch.serve --arch ssh-ecg --no-smoke
     PYTHONPATH=src python -m repro.launch.serve --arch ssh-ecg --sequential
     PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b --smoke
 """
@@ -22,13 +23,15 @@ import numpy as np
 
 from repro.configs import get_arch
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 
 SERVE_LENGTH = 128
 
 
-def _ssh_db(arch, config, db_dir=None):
+def _ssh_db(arch, config, db_dir=None, smoke: bool = True):
     """(queries pool, TimeSeriesDB) — loaded from ``db_dir`` when it holds
-    a saved database, else built from the synthetic smoke stream.
+    a saved database, else built from a synthetic ECG stream with the
+    arch's smoke encoder (``smoke``) or its full published widths.
 
     A loaded database keeps its *saved* search knobs (topk/top_c/band
     were chosen for its series length); only the serving-policy fields
@@ -62,7 +65,7 @@ def _ssh_db(arch, config, db_dir=None):
     series = jnp.asarray(extract_subsequences(stream, length,
                                               stride=1, znorm=True))
     if tsdb is None:
-        tsdb = TimeSeriesDB.build(series, spec=arch.index_spec(smoke=True),
+        tsdb = TimeSeriesDB.build(series, spec=arch.index_spec(smoke=smoke),
                                   config=config)
     return series, tsdb
 
@@ -70,7 +73,7 @@ def _ssh_db(arch, config, db_dir=None):
 def serve_ssh(arch, requests: int, batch_size: int, wait_ms: float,
               backend: str = "auto", db_dir=None, replication: int = 1,
               fleet_workers=None, hedge_ms: float = 30.0,
-              batch_mode: str = "fixed"):
+              batch_mode: str = "fixed", smoke: bool = True):
     """Engine-based serving: dynamic batching + batched probe/re-rank.
 
     ``batch_mode="adaptive"`` lets the batcher set its own wait from the
@@ -93,7 +96,7 @@ def serve_ssh(arch, requests: int, batch_size: int, wait_ms: float,
               f"(overriding arch multiprobe_offsets="
               f"{cfg.multiprobe_offsets})")
         cfg = cfg.replace(multiprobe_offsets=1)
-    db, tsdb = _ssh_db(arch, cfg, db_dir)
+    db, tsdb = _ssh_db(arch, cfg, db_dir, smoke=smoke)
     engine = tsdb.engine
     rng = np.random.default_rng(0)
     qids = rng.integers(0, db.shape[0], requests)
@@ -125,11 +128,11 @@ def serve_ssh(arch, requests: int, batch_size: int, wait_ms: float,
 
 
 def serve_ssh_sequential(arch, requests: int, backend: str = "auto",
-                         db_dir=None):
+                         db_dir=None, smoke: bool = True):
     """Pre-engine baseline: the sequential ``local`` searcher."""
     cfg = arch.search_config(length=SERVE_LENGTH, searcher="local",
                              backend=backend)
-    db, tsdb = _ssh_db(arch, cfg, db_dir)
+    db, tsdb = _ssh_db(arch, cfg, db_dir, smoke=smoke)
     rng = np.random.default_rng(0)
     lat = []
     for i in rng.integers(0, db.shape[0], requests):
@@ -198,19 +201,24 @@ def main():
                     help="fleet size (default max(2, replication))")
     ap.add_argument("--hedge-ms", type=float, default=30.0,
                     help="hedging deadline floor in ms (fleet only)")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced smoke widths (default); --no-smoke "
+                         "builds with the arch's published widths")
     args = ap.parse_args()
+    enable_compile_cache()
     arch = get_arch(args.arch)
     if arch.family == "ssh":
         if args.sequential:
             serve_ssh_sequential(arch, args.requests, backend=args.backend,
-                                 db_dir=args.db_dir)
+                                 db_dir=args.db_dir, smoke=args.smoke)
         else:
             serve_ssh(arch, args.requests, args.batch_size, args.wait_ms,
                       backend=args.backend, db_dir=args.db_dir,
                       replication=args.replication,
                       fleet_workers=args.fleet_workers,
-                      hedge_ms=args.hedge_ms, batch_mode=args.batch_mode)
+                      hedge_ms=args.hedge_ms, batch_mode=args.batch_mode,
+                      smoke=args.smoke)
     elif arch.family == "lm":
         serve_lm(arch, args.requests, args.smoke)
     else:

@@ -50,6 +50,7 @@ from typing import List, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.dtw import BIG
 from repro.core.index import SSHIndex
 from repro.core.rerank import SearchStats
 from repro.core.search import SearchResult
@@ -173,9 +174,12 @@ class DistributedSearcher:
                 self.config.backend)))
         if timer.enabled:
             stats.stage_seconds = dict(timer.timings)
+        dists = np.stack(dists).astype(np.float32)
+        # filler slots (fewer colliding rows than topk) carry id -1, as
+        # the batched path's do, so per_query trims them
+        ids = np.where(dists < BIG * 0.5, np.stack(ids), -1)
         return BatchSearchResult(
-            ids=np.stack(ids).astype(np.int64),
-            dists=np.stack(dists).astype(np.float32),
+            ids=ids.astype(np.int64), dists=dists,
             n_queries=b, n_database=n, n_union=min(top_c, n),
             n_candidates=np.full(b, min(top_c, n), np.int64),
             pruned_by_hash_frac=np.full(b, 1.0 - min(top_c, n) / n),
