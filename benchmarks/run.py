@@ -97,8 +97,6 @@ def _parse_args(argv):
 
 def main(argv=None) -> int:
     args = _parse_args(argv)
-    from repro.launch.compile_cache import enable_compile_cache
-    enable_compile_cache()
     if args.scale:
         # must land before benchmarks.common is imported (it reads the
         # env at import time to size the datasets)
@@ -123,6 +121,8 @@ def main(argv=None) -> int:
                   f"module; valid module names: {names}", file=sys.stderr)
             return 2
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.bench import BenchRunner
     from benchmarks import common
     runner = BenchRunner(scale=common.SCALE, out_dir=args.out,

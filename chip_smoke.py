@@ -12,12 +12,12 @@ Data is synthetic ECG made from ``--seed``.
 
 One chip, in order:
 
-* archive — builds ``--rows`` (default 2^20) z-normalised length-128
-  windows into a device-resident index and prints its bytes;
+* archive — builds 2^20 z-normalised length-128 windows into a
+  device-resident index and prints its bytes;
 * query   — warms every batch bucket, serves 32 requests through the
   engine (each must find itself first), compares 16 of them with
   ``backend="jnp"`` on the chip (identical top-k ids) and reports
-  precision@10 against brute-force DTW;
+  precision@10 against brute-force DTW (the plain jnp reference);
 * long    — the same path at length 2048 and the paper's 5% band;
 * ingest  — an ``"ssh-cs"`` database grown by ``add_stream``/``flush``,
   queried for a row that was streamed in.
@@ -25,6 +25,9 @@ One chip, in order:
 ``--chips 4`` runs only the ``distributed`` searcher (a ``shard_map``
 over every local device) and requires its ids to equal the ``batched``
 searcher's on one chip for the same index and ``SearchConfig``.
+
+Each phase sets ``top_c`` to hold the largest group of rows that share
+one whole signature (see ``fit_top_c``).
 
 The script exits non-zero, printing no result, when JAX finds no TPU,
 when ``backend="auto"`` does not resolve to the Pallas kernels, or when
@@ -34,6 +37,7 @@ the device.  Timings printed on earlier lines are information only.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import sys
 import time
@@ -50,16 +54,15 @@ from repro.db import BatchPolicy, TimeSeriesDB          # noqa: E402
 from repro.encoders import IndexSpec                    # noqa: E402
 
 ARCH = get_arch("ssh-ecg")
+ARCHIVE_ROWS = 1 << 20
+PHASE_ROWS = 65536          # long, ingest and distributed phases
 MAX_BATCH = 8
 RESULT_TIMEOUT_S = 600.0
-# At length 128 the paper's encoder (W=80, δ=3, n=15) sees 17 sketch bits,
-# i.e. 3 shingles per window.  Over 2^20 stride-1 ECG windows most rows
-# share those 17 bits — and so every one of their K hashes — with
-# thousands of others (median group 3790, largest 19719, seed 0), and the
-# probe's top-C breaks such ties by lowest id.  The archive therefore
-# re-ranks a top-C that holds a whole group; the arch default (512)
-# would drop the query itself for most rows.
-ARCHIVE_TOP_C = 32768
+# JAX's own compile-time events (jax.monitoring): the XLA backend compile
+# of one program, a load from the persistent cache included, and a hit
+# in that cache.
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 def log(msg: str) -> None:
@@ -96,13 +99,70 @@ def build(series: np.ndarray, spec, config):
     return db, dev_series, time.perf_counter() - t0
 
 
-def warm_buckets(db, queries) -> float:
-    """Compile every padded batch size the engine may form; seconds."""
-    t0 = time.perf_counter()
+@contextlib.contextmanager
+def compile_clock():
+    """Yields a dict that sums, while the block runs, the seconds JAX
+    reports for backend compiles (``compile_s``) and the persistent-cache
+    hits (``cache_hits``)."""
+    import jax
+    got = {"compile_s": 0.0, "cache_hits": 0}
+
+    def on_duration(event, secs, **_):
+        if event == BACKEND_COMPILE_EVENT:
+            got["compile_s"] += secs
+
+    def on_event(event, **_):
+        if event == CACHE_HIT_EVENT:
+            got["cache_hits"] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+    try:
+        yield got
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+        jax.monitoring.unregister_event_listener(on_event)
+
+
+def warm_buckets(db, queries) -> str:
+    """Run one batch of every padded size the engine may form; returns
+    the wall seconds (compile and first execution) and, apart, the
+    backend compile seconds JAX reported in them."""
     n = int(queries.shape[0])
-    for size in db.config.buckets():
-        db.engine.searcher.search_batch(queries[np.arange(size) % n])
-    return time.perf_counter() - t0
+    with compile_clock() as clock:
+        t0 = time.perf_counter()
+        for size in db.config.buckets():
+            db.engine.searcher.search_batch(queries[np.arange(size) % n])
+        wall = time.perf_counter() - t0
+    return (f"warmup_seconds={wall:.3f} "
+            f"warmup_compile_seconds={clock['compile_s']:.3f} "
+            f"compile_cache_hits={clock['cache_hits']}")
+
+
+def fit_top_c(db, what: str) -> None:
+    """Raise ``db``'s top_c to hold the largest group of rows that share
+    one whole signature.
+
+    Rows with the query's signature tie with it at the top collision
+    count, and the probe keeps the lowest ids of a tie, so a top_c below
+    the query's group size can cut the query itself.  At length 128 the
+    paper's ECG encoder (W=80, δ=3, n=15) sees 17 sketch bits (3
+    shingles), and over 2^20 stride-1 windows most rows share theirs with
+    thousands of others: the arch default top_c fails there.
+    """
+    sig = np.ascontiguousarray(np.asarray(db.index.signatures))
+    rows = sig.view(np.dtype((np.void, sig.itemsize * sig.shape[1])))
+    sizes = np.unique(rows.ravel(), return_counts=True)[1]
+    largest = int(sizes.max())
+    default = db.config.top_c
+    top_c = max(default, 1 << (largest - 1).bit_length())
+    per_row = np.repeat(sizes, sizes)
+    log(f"{what}: signature tie groups={len(sizes)} "
+        f"median_group_of_a_row={int(np.median(per_row))} "
+        f"largest={largest} rows_in_groups_over_default="
+        f"{float(np.mean(per_row > default)):.4f} top_c={top_c} "
+        f"(default {default})")
+    db.reconfigure(top_c=top_c)
 
 
 def serve(db, queries):
@@ -133,22 +193,22 @@ def require_self_match(results, qids, what: str) -> None:
                    f"{bad[:8]}")
 
 
-def phase_archive(rows: int, seed: int, *, spec=None, backend="auto",
-                  top_c: int = ARCHIVE_TOP_C):
+def phase_archive(rows: int, seed: int, *, backend="auto"):
     """Build the length-128 ECG archive behind the engine searcher."""
     import jax
-    spec = spec if spec is not None else ARCH.index_spec()
+    spec = ARCH.index_spec()
     cfg = ARCH.search_config(
-        length=128, searcher="engine", backend=backend, top_c=top_c,
+        length=128, searcher="engine", backend=backend,
         batch_policy=BatchPolicy(max_batch=MAX_BATCH, max_wait_ms=2.0))
     series = ecg_windows(rows, 128, seed)
     db, dev_series, build_s = build(series, spec, cfg)
+    fit_top_c(db, "archive")
     nbytes = db.index.nbytes()
     stats = jax.devices()[0].memory_stats() or {}
     limit = stats.get("bytes_limit")
     share = f" ({nbytes / limit:.1%} of {limit} B)" if limit else ""
     log(f"archive: rows={len(db)} length=128 spec={spec.to_dict()} "
-        f"top_c={cfg.top_c} band={cfg.band}")
+        f"top_c={db.config.top_c} band={cfg.band}")
     log(f"archive: index_device_bytes={nbytes}{share} "
         f"build_seconds={build_s:.3f}")
     return db, dev_series
@@ -163,8 +223,9 @@ def phase_query(db, dev_series, seed: int, *, n_requests: int = 32,
     rng = np.random.default_rng(seed + 1)
     qids = rng.choice(len(db), size=n_requests, replace=False)
     queries = dev_series[qids]
-    compile_s = warm_buckets(db, queries)
-    results, lat = serve(db, queries)
+    warm = warm_buckets(db, queries)
+    with compile_clock() as clock:
+        results, lat = serve(db, queries)
     require_self_match(results, qids, "query")
 
     cfg = db.config
@@ -184,59 +245,62 @@ def phase_query(db, dev_series, seed: int, *, n_requests: int = 32,
         rel = np.abs(g - w) / np.maximum(np.abs(w), 1e-30)
         rel[(g == 0) & (w == 0)] = 0.0
         worst = max(worst, float(rel.max(initial=0.0)))
-    prec = []
-    t0 = time.perf_counter()
+    prec, brute_s = [], []
     for q, got in zip(sub, results):
+        t0 = time.perf_counter()
         gold, _ = brute_force_topk(dev_series[int(q)], dev_series,
                                    cfg.topk, band=cfg.band)
+        brute_s.append(time.perf_counter() - t0)
         prec.append(precision_at_k(got.ids, gold, cfg.topk))
-    brute_s = time.perf_counter() - t0
     peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
     log(f"query: requests={n_requests} self_match={n_requests}/{n_requests}"
         f" pallas_vs_jnp_ids_equal={len(sub)}/{len(sub)} "
         f"max_rel_dist_diff={worst:.3e} jnp_seconds={jnp_s:.3f}")
     log(f"query: precision@{cfg.topk}={np.mean(prec):.4f} over {len(sub)} "
         f"queries vs brute_force_topk (min {min(prec):.2f}) "
-        f"brute_force_seconds={brute_s:.3f}")
+        f"brute_force_seconds={sum(brute_s):.3f} "
+        f"(first {brute_s[0]:.3f})")
     log(f"query: p50_ms={np.percentile(lat, 50) * 1e3:.3f} "
         f"p99_ms={np.percentile(lat, 99) * 1e3:.3f} "
-        f"compile_seconds={compile_s:.3f} peak_bytes_in_use={peak}")
+        f"serve_compile_seconds={clock['compile_s']:.3f} {warm} "
+        f"peak_bytes_in_use={peak}")
     return {"precision": float(np.mean(prec)), "max_rel_diff": worst}
 
 
-def phase_long(rows: int, seed: int, *, spec=None, backend="auto",
+def phase_long(rows: int, seed: int, *, backend="auto",
                length: int = 2048, n_requests: int = 8):
     """The engine path at a long query length and the 5% band."""
-    spec = spec if spec is not None else ARCH.index_spec()
     cfg = ARCH.search_config(
         length=length, searcher="engine", backend=backend,
         batch_policy=BatchPolicy(max_batch=MAX_BATCH, max_wait_ms=2.0))
     series = ecg_windows(rows, length, seed + 2)
-    db, dev_series, build_s = build(series, spec, cfg)
+    db, dev_series, build_s = build(series, ARCH.index_spec(), cfg)
+    fit_top_c(db, "long")
     with db:
         qids = np.random.default_rng(seed + 3).choice(
             len(db), size=n_requests, replace=False)
         queries = dev_series[qids]
-        compile_s = warm_buckets(db, queries)
+        warm = warm_buckets(db, queries)
         results, lat = serve(db, queries)
         require_self_match(results, qids, f"long (m={length})")
     log(f"long: rows={rows} length={length} band={cfg.band} "
-        f"build_seconds={build_s:.3f} compile_seconds={compile_s:.3f} "
+        f"top_c={db.config.top_c} build_seconds={build_s:.3f} {warm} "
         f"self_match={n_requests}/{n_requests} "
         f"p50_ms={np.percentile(lat, 50) * 1e3:.3f}")
 
 
 def phase_ingest(rows: int, seed: int, *, backend="auto", length=2048,
-                 blocks: int = 4, block_rows: int = 256, params=None):
+                 blocks: int = 4, block_rows: int = 256):
     """``"ssh-cs"`` database grown by streamed blocks, then queried for
     rows that were streamed in (the count-sketch kernel's path)."""
-    spec = IndexSpec(encoder="ssh-cs", params=params or {})
+    spec = IndexSpec(encoder="ssh-cs")
     cfg = ARCH.search_config(
         length=length, searcher="engine", backend=backend,
         batch_policy=BatchPolicy(max_batch=MAX_BATCH, max_wait_ms=2.0))
     base = ecg_windows(rows, length, seed + 4)
     extra = ecg_windows(blocks * block_rows, length, seed + 5)
     db, _, build_s = build(base, spec, cfg)
+    fit_top_c(db, "ingest")
     with db:
         t0 = time.perf_counter()
         for b in range(blocks):
@@ -255,7 +319,7 @@ def phase_ingest(rows: int, seed: int, *, backend="auto", length=2048,
         f"stream_fold_seconds={ingest_s:.3f} self_match=4/4")
 
 
-def phase_distributed(rows: int, seed: int, *, spec=None, backend="auto",
+def phase_distributed(rows: int, seed: int, *, backend="auto",
                       n_queries: int = 16):
     """``distributed`` (shard_map over every local device) vs ``batched``
     (one device) on the same index and config: ids must be equal.
@@ -267,12 +331,11 @@ def phase_distributed(rows: int, seed: int, *, spec=None, backend="auto",
     one by design.
     """
     import jax
-    spec = spec if spec is not None else ARCH.index_spec()
     cfg = ARCH.search_config(length=128, searcher="batched",
                              backend=backend, top_c=rows,
                              multiprobe_offsets=1)
     series = ecg_windows(rows, 128, seed + 7)
-    db, dev_series, build_s = build(series, spec, cfg)
+    db, dev_series, build_s = build(series, ARCH.index_spec(), cfg)
     mesh = jax.make_mesh((jax.device_count(),), ("data",))
     qids = np.random.default_rng(seed + 8).choice(rows, size=n_queries,
                                                   replace=False)
@@ -301,8 +364,6 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the distributed-vs-batched phase")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--rows", type=int, default=1 << 20,
-                    help="archive rows (length 128)")
     args = ap.parse_args(argv)
 
     from repro.launch.compile_cache import enable_compile_cache
@@ -328,17 +389,17 @@ def main(argv=None) -> int:
     t0 = time.perf_counter()
     try:
         if args.chips == 4:
-            phase_distributed(65536, args.seed)
+            phase_distributed(PHASE_ROWS, args.seed)
         else:
-            db, dev_series = phase_archive(args.rows, args.seed)
+            db, dev_series = phase_archive(ARCHIVE_ROWS, args.seed)
             log(f"elapsed_seconds={time.perf_counter() - t0:.3f}")
             with db:
                 phase_query(db, dev_series, args.seed)
             del db, dev_series
             log(f"elapsed_seconds={time.perf_counter() - t0:.3f}")
-            phase_long(65536, args.seed)
+            phase_long(PHASE_ROWS, args.seed)
             log(f"elapsed_seconds={time.perf_counter() - t0:.3f}")
-            phase_ingest(65536, args.seed)
+            phase_ingest(PHASE_ROWS, args.seed)
     except SmokeFailure as e:
         sys.exit(f"chip_smoke: FAIL — {e}")
     log(f"total_seconds={time.perf_counter() - t0:.3f}")

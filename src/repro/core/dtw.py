@@ -126,9 +126,12 @@ def dtw_batch(query: jnp.ndarray, candidates: jnp.ndarray,
 # kernel's O(m·band) cell count, plus threshold-based early abandoning
 # ---------------------------------------------------------------------------
 
-def _banded_column(x, y_j, j, W_prev, r, m):
-    """One column of the window DP: W[u] = D[j - r + u, j], u in [0, 2r+1).
+def _banded_column(xw, y_j, j, W_prev, r, m):
+    """One column of the window DP for a block of candidate lanes:
+    W[u, c] = D_c[j - r + u, j], u in [0, 2r+1).
 
+    ``xw`` (2r+1, 1 | C) holds the query values at the window's rows
+    (index-clamped), ``y_j`` (C,) the candidates' values at column j.
     Same (min,+) cumsum/cummin identity as ``_column_update``, applied to
     the (2r+1)-wide band window instead of the full column — O(m·band)
     total cells, matching the Pallas wavefront's work bound.  Window
@@ -136,23 +139,61 @@ def _banded_column(x, y_j, j, W_prev, r, m):
     D[i-1, j-1] at slot u.  Out-of-matrix slots carry BIG; their (index-
     clamped) costs inside the cumsum cancel exactly because the valid
     slots of a window are contiguous (C_i - C_{k-1} only ever spans valid
-    slots for a valid (k, i) pair).
+    slots for a valid (k, i) pair).  The window runs down the leading
+    axis and the candidates along the trailing one, so a TPU keeps the
+    (2r+1, C) carry on its lanes instead of padding 2r+1 out to 128.
     """
-    w = 2 * r + 1
-    u = jnp.arange(w)
+    u = jnp.arange(2 * r + 1)[:, None]
     i = j - r + u                               # row index of window slot u
-    cost = (x[jnp.clip(i, 0, m - 1)] - y_j) ** 2
-    up_shift = jnp.concatenate([W_prev[1:],
-                                jnp.full((1,), BIG, W_prev.dtype)])
+    cost = (xw - y_j) ** 2
+    up_shift = jnp.concatenate(
+        [W_prev[1:], jnp.full((1, W_prev.shape[1]), BIG, W_prev.dtype)])
     e = jnp.minimum(W_prev, up_shift)           # min(D[i-1,j-1], D[i,j-1])
     # j == 0: no left column at all; the path starts at (0, 0) = slot r
     e0 = jnp.where(u == r, 0.0, BIG)
     e = jnp.where(j == 0, e0, e)
-    csum = jnp.cumsum(cost)
-    shifted = jnp.concatenate([jnp.zeros((1,), csum.dtype), csum[:-1]])
-    run = jax.lax.associative_scan(jnp.minimum, e - shifted)
+    csum = jnp.cumsum(cost, axis=0)
+    shifted = jnp.concatenate([jnp.zeros_like(csum[:1]), csum[:-1]])
+    run = jax.lax.associative_scan(jnp.minimum, e - shifted, axis=0)
     col = jnp.minimum(csum + run, BIG)
     return jnp.where((i >= 0) & (i < m), col, BIG)
+
+
+def _dtw_banded_lanes(xs: jnp.ndarray, ys: jnp.ndarray, band: int,
+                      threshold: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """Window-DP DTW over time-major arrays: ``xs`` (m, 1 | C) query
+    (one shared or one per lane), ``ys`` (m, C) candidates -> (C,).
+
+    A lane stops at the first column whose window minimum exceeds its
+    threshold (its window is then frozen); the loop ends when every lane
+    has stopped or the last column is done.
+    """
+    xs = xs.astype(jnp.float32)
+    ys = ys.astype(jnp.float32)
+    m, c = ys.shape
+    assert xs.shape[0] == m, "dtw_banded requires equal lengths"
+    r = min(band, m - 1)
+    win = jnp.arange(2 * r + 1)
+    thr = jnp.float32(BIG) if threshold is None \
+        else jnp.asarray(threshold, jnp.float32)
+
+    def cond(carry):
+        j, _, alive = carry
+        return (j < m) & jnp.any(alive)
+
+    def body(carry):
+        j, W, alive = carry
+        xw = xs[jnp.clip(j - r + win, 0, m - 1)]
+        W = jnp.where(alive, _banded_column(xw, ys[j], j, W, r, m), W)
+        return j + 1, W, alive & (jnp.min(W, axis=0) <= thr)
+
+    _, W, _ = jax.lax.while_loop(
+        cond, body, (0, jnp.full((2 * r + 1, c), BIG, jnp.float32),
+                     jnp.ones((c,), bool)))
+    out = W[r]                                  # D[m-1, m-1]
+    if threshold is None:
+        return out
+    return jnp.where(out > thr, BIG, out)
 
 
 @functools.partial(jax.jit, static_argnames=("band",))
@@ -169,28 +210,7 @@ def dtw_banded(x: jnp.ndarray, y: jnp.ndarray, band: int,
     threshold-aware Pallas wavefront.  ``None`` runs all columns and
     returns the exact value.
     """
-    x = x.astype(jnp.float32)
-    y = y.astype(jnp.float32)
-    m = x.shape[0]
-    assert y.shape[0] == m, "dtw_banded requires equal lengths"
-    r = min(band, m - 1)
-    thr = jnp.float32(BIG) if threshold is None \
-        else jnp.asarray(threshold, jnp.float32)
-
-    def cond(carry):
-        j, W = carry
-        return (j < m) & ((j == 0) | (jnp.min(W) <= thr))
-
-    def body(carry):
-        j, W = carry
-        return j + 1, _banded_column(x, y[j], j, W, r, m)
-
-    _, W = jax.lax.while_loop(
-        cond, body, (0, jnp.full((2 * r + 1,), BIG, jnp.float32)))
-    out = W[r]                                  # D[m-1, m-1]
-    if threshold is None:
-        return out
-    return jnp.where(out > thr, BIG, out)
+    return _dtw_banded_lanes(x[:, None], y[:, None], band, threshold)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("band",))
@@ -198,29 +218,18 @@ def dtw_banded_batch(query: jnp.ndarray, candidates: jnp.ndarray, band: int,
                      threshold: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Banded window-DP DTW of one query vs a batch: (C, m) -> (C,).
 
-    ``threshold`` broadcasts over lanes (scalar or (C,)).  Under vmap the
-    while_loop runs until every lane is done or abandoned, so a block of
-    hopeless candidates exits after a prefix of the columns.
+    ``threshold`` broadcasts over lanes (scalar or (C,)); the column scan
+    stops once every lane is done or abandoned, so a block of hopeless
+    candidates exits after a prefix of the columns.
     """
-    if threshold is None:
-        return jax.vmap(lambda c: dtw_banded(query, c, band))(candidates)
-    thr = jnp.broadcast_to(jnp.asarray(threshold, jnp.float32),
-                           candidates.shape[:1])
-    return jax.vmap(lambda c, t: dtw_banded(query, c, band, t)
-                    )(candidates, thr)
+    return _dtw_banded_lanes(query[:, None], candidates.T, band, threshold)
 
 
 @functools.partial(jax.jit, static_argnames=("band",))
 def dtw_banded_pairs(queries: jnp.ndarray, candidates: jnp.ndarray, band: int,
                      threshold: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Row-aligned banded window-DP DTW: (P, m) x (P, m) -> (P,)."""
-    if threshold is None:
-        return jax.vmap(lambda q, c: dtw_banded(q, c, band)
-                        )(queries, candidates)
-    thr = jnp.broadcast_to(jnp.asarray(threshold, jnp.float32),
-                           candidates.shape[:1])
-    return jax.vmap(lambda q, c, t: dtw_banded(q, c, band, t)
-                    )(queries, candidates, thr)
+    return _dtw_banded_lanes(queries.T, candidates.T, band, threshold)
 
 
 @functools.partial(jax.jit, static_argnames=("band",))

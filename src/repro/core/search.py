@@ -22,6 +22,7 @@ from repro.core.index import SSHIndex
 from repro.core.rerank import SearchStats
 from repro.db.config import SearchConfig, config_from_legacy_kwargs
 from repro.kernels import ops
+from repro.kernels import ref as kref
 
 
 @dataclasses.dataclass
@@ -212,14 +213,12 @@ def brute_force_topk(query: jnp.ndarray, series: jnp.ndarray, topk: int,
                      band: Optional[int] = None):
     """Gold standard (paper §5.3): exact DTW over the whole database.
 
-    Routed through the same DTW dispatch as the production re-rank
-    (``ops.dtw_rerank``, backend ``"auto"``) so the gold distances use
-    the same arithmetic — the jnp reference's banded window DP off-TPU,
-    keeping exactness tests ulp-comparable, and the wavefront kernel on
-    a TPU, where the window DP's (N, 2·band+1) working arrays run about a
-    hundred times slower.
+    Always the plain jnp reference (``kernels.ref``), on every platform,
+    so the gold never runs the Pallas kernels it judges; it shares the
+    banded window-DP's summation order with the re-rank's jnp backend,
+    keeping exactness tests ulp-comparable.
     """
-    d = ops.dtw_rerank(query, series, band)
+    d = kref.dtw_wavefront_ref(query, series, band=band)
     vals, idx = jax.lax.top_k(-d, topk)
     return np.asarray(idx), np.asarray(-vals)
 

@@ -29,8 +29,7 @@ def _cpu_env(**extra):
 
 
 def test_smoke_phases_pallas_interpret():
-    db, dev_series = chip_smoke.phase_archive(1024, 0, backend="pallas",
-                                              top_c=512)
+    db, dev_series = chip_smoke.phase_archive(1024, 0, backend="pallas")
     with db:
         out = chip_smoke.phase_query(db, dev_series, 0, n_requests=8,
                                      n_compare=4)
