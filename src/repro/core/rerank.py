@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.bench.timing import DISABLED, StageTimer
+from repro.bench.timing import DISABLED, StageTimer, to_host
 from repro.core import lower_bounds as lb
 from repro.core.index import SSHIndex
 from repro.kernels import ops
@@ -164,8 +164,8 @@ def dtw_pairs_chunked(q_rows: jnp.ndarray, c_rows: jnp.ndarray,
     # concat on a fresh (P, m) shape compiles per distinct P (see the
     # union-table comment in rerank_batch) — only the fixed-shape
     # chunks below may touch the device
-    q_rows = np.asarray(q_rows)
-    c_rows = np.asarray(c_rows)
+    q_rows = to_host(q_rows)
+    c_rows = to_host(c_rows)
     thr = None
     if threshold is not None:
         thr = np.broadcast_to(
@@ -178,7 +178,7 @@ def dtw_pairs_chunked(q_rows: jnp.ndarray, c_rows: jnp.ndarray,
     out, i, total = [], 0, p + pad
     for chunk in (PAIR_CHUNK, PAIR_CHUNK_SMALL):
         while total - i >= chunk:
-            out.append(np.asarray(ops.dtw_rerank_pairs(
+            out.append(to_host(ops.dtw_rerank_pairs(
                 q_rows[i:i + chunk], c_rows[i:i + chunk], band,
                 use_pallas=use_pallas,
                 threshold=None if thr is None else thr[i:i + chunk])))
@@ -203,15 +203,15 @@ def lb_improved_pairs_chunked(q_rows: jnp.ndarray, c_rows: jnp.ndarray,
     if not p:
         return np.zeros(0, np.float32)
     pad = (-p) % PAIR_CHUNK_SMALL
-    q_rows = np.asarray(q_rows)        # host-side pad: see dtw_pairs_chunked
-    c_rows = np.asarray(c_rows)
+    q_rows = to_host(q_rows)           # host-side pad: see dtw_pairs_chunked
+    c_rows = to_host(c_rows)
     if pad:
         q_rows = np.concatenate([q_rows, q_rows[:1].repeat(pad, 0)], 0)
         c_rows = np.concatenate([c_rows, c_rows[:1].repeat(pad, 0)], 0)
     out, i, total = [], 0, p + pad
     for chunk in (PAIR_CHUNK, PAIR_CHUNK_SMALL):
         while total - i >= chunk:
-            out.append(np.asarray(lb.lb_improved_pairs(
+            out.append(to_host(lb.lb_improved_pairs(
                 q_rows[i:i + chunk], c_rows[i:i + chunk], band)))
             i += chunk
     return np.concatenate(out)[:p]
@@ -389,8 +389,8 @@ def rerank_batch(queries: jnp.ndarray, ids: np.ndarray, valid: np.ndarray,
     if cascade_on:
         with timer.stage("lb"):
             seed_series = index.series[jnp.asarray(ids[:, :seed_k])]
-            seed_d = np.asarray(_seed_dtw_backend(queries, seed_series,
-                                                  band, backend))
+            seed_d = to_host(_seed_dtw_backend(queries, seed_series,
+                                               band, backend))
             if seed_size is not None:
                 # a widened seed may overrun a row's valid candidates
                 # (only possible when seed_k > topk); mask those slots so
@@ -409,7 +409,7 @@ def rerank_batch(queries: jnp.ndarray, ids: np.ndarray, valid: np.ndarray,
                                                best, env[0], env[1])
             else:
                 k1, k2, k3 = _cascade_rows(queries, cand_series, band, best)
-            k1, k2, k3 = np.asarray(k1), np.asarray(k2), np.asarray(k3)
+            k1, k2, k3 = to_host(k1), to_host(k2), to_host(k3)
             # sequential skips the cascade entirely when n_hash <= topk,
             # and never drops the seeded set; the first seed_k slots ARE
             # the first seed_k valid candidates whenever the cascade
@@ -431,7 +431,7 @@ def rerank_batch(queries: jnp.ndarray, ids: np.ndarray, valid: np.ndarray,
             # and their seed kth may not upper-bound anything — +inf
             # exempts them from both LB_Improved and early abandoning
             thr_rows = np.where(np.asarray(n_hash) > topk,
-                                np.asarray(best, np.float32),
+                                to_host(best).astype(np.float32),
                                 np.float32(np.inf)).astype(np.float32)
     else:
         ok = valid
@@ -447,11 +447,15 @@ def rerank_batch(queries: jnp.ndarray, ids: np.ndarray, valid: np.ndarray,
     rows_idx, cols_idx = np.nonzero(ok)                   # (P,) row-major
     pair_ids = ids[rows_idx, cols_idx]
     union = np.unique(pair_ids)                           # (U,) sorted
-    series_np = np.asarray(index.series)   # zero-copy view on CPU jax
-    union_series = series_np[union]                       # (U, m)
-    pos = np.searchsorted(union, pair_ids)
-    c_rows = union_series[pos]                            # (P, m)
-    q_rows = np.asarray(queries)[rows_idx]                # (P, m)
+    # a jax.Array keeps the host copy of its first np.asarray: the
+    # series' is made once per index, the queries' by batch_probe's
+    # encode, so neither crosses from the device here (no ssh.fetch)
+    with timer.span("pairs", pairs=len(pair_ids), union=len(union)):
+        series_np = np.asarray(index.series)   # zero-copy view on CPU jax
+        union_series = series_np[union]                   # (U, m)
+        pos = np.searchsorted(union, pair_ids)
+        c_rows = union_series[pos]                        # (P, m)
+        q_rows = np.asarray(queries)[rows_idx]            # (P, m)
 
     if cascade_on:
         with timer.stage("lb_improved") as sync:
@@ -472,7 +476,7 @@ def rerank_batch(queries: jnp.ndarray, ids: np.ndarray, valid: np.ndarray,
             c_rows = c_rows[keep_pair]
     n_final = ok.sum(axis=1)                              # (B,)
 
-    with timer.stage("dtw") as sync:
+    with timer.stage("dtw", pairs=len(q_rows)) as sync:
         thr_pairs = (thr_rows[rows_idx]
                      if (cascade_on and early_abandon) else None)
         pair_d = dtw_pairs_chunked(q_rows, c_rows, band, backend,
@@ -485,9 +489,9 @@ def rerank_batch(queries: jnp.ndarray, ids: np.ndarray, valid: np.ndarray,
         cand_d = np.full((b, c), BIG, np.float32)         # candidate order
         cand_d[rows_idx, cols_idx] = pair_d
         neg, idx = jax.lax.top_k(-jnp.asarray(cand_d), k_out)
-        idx = np.asarray(idx)
+        idx = to_host(idx)
         out_ids = np.take_along_axis(ids, idx, axis=1)
-        out_d = -np.asarray(sync(neg))
+        out_d = -to_host(sync(neg))
     # rows with fewer than k_out survivors: mark the filler tail (fixed
     # output shapes; callers trim these, matching sequential lengths)
     out_ids = np.where(out_d < BIG * 0.5, out_ids, -1)

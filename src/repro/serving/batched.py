@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.bench.timing import DISABLED, STAGES, StageTimer
+from repro.bench.timing import DISABLED, STAGES, StageTimer, to_host
 from repro.core import minhash
 from repro.core import rerank as rr
 from repro.core.index import SSHIndex
@@ -94,12 +94,14 @@ def batch_probe(queries: jnp.ndarray, index: SSHIndex, top_c: int,
                 interpret: bool = False,
                 timer: StageTimer = DISABLED,
                 probe_stats: Optional[dict] = None):
-    """Stage 1+2 for a query block: (B, m) -> ids (B, C), counts (B, C).
+    """Stage 1+2 for a query block: (B, m) -> host arrays ids (B, C)
+    int64, counts (B, C).
 
     Per-row decisions identical to the sequential ``hash_probe``: the same
-    collision counts feed the same ``lax.top_k`` (ties → lowest id).
-    An enabled ``timer`` records the batched signature build as
-    ``encode`` and the collision scan + top-C as ``probe``.
+    collision counts feed the same ``lax.top_k`` (ties → lowest id), and a
+    row with no positive count falls back to the first C ids.  An enabled
+    ``timer`` records the batched signature build as ``encode`` and the
+    collision scan + top-C, its fetch and the fallback as ``probe``.
 
     Rows ride the index's signature LRU: when EVERY row is cached the
     batched encode dispatch is skipped entirely (``probe_stats`` gets
@@ -115,7 +117,7 @@ def batch_probe(queries: jnp.ndarray, index: SSHIndex, top_c: int,
                else "sig")
     with timer.stage("encode") as sync:
         cache = index._sig_cache()
-        rows = np.asarray(queries)
+        rows = to_host(queries)
         keys = [cache.key(rows[i], index.enc.spec, index.build_backend,
                           variant) for i in range(b)]
         cached = [cache.get(k) for k in keys]
@@ -129,7 +131,7 @@ def batch_probe(queries: jnp.ndarray, index: SSHIndex, top_c: int,
         else:
             sigs = index.query_signatures_batch(queries)  # (B, K)
         if not hits:
-            sig_rows = np.asarray(sigs)
+            sig_rows = to_host(sigs)
             for i in range(b):
                 cache.put(keys[i], sig_rows[i])
         if probe_stats is not None:
@@ -150,6 +152,11 @@ def batch_probe(queries: jnp.ndarray, index: SSHIndex, top_c: int,
             counts = counts.reshape(b, multiprobe_offsets, -1).max(axis=1)
         vals, ids = jax.lax.top_k(counts, top_c)
         ids, vals = sync((ids, vals))
+        ids = to_host(ids).astype(np.int64)
+        vals = to_host(vals)
+        empty = ~(vals > 0).any(axis=1)
+        if empty.any():        # degenerate rows: same fallback as sequential
+            ids[empty] = np.arange(top_c, dtype=np.int64)[None, :]
     return ids, vals
 
 
@@ -195,17 +202,13 @@ def ssh_search_batch(queries: jnp.ndarray, index: SSHIndex,
 
     # -- stages 1+2: fused probe ------------------------------------------
     probe_stats: dict = {}
-    ids_j, vals_j = batch_probe(queries, index, c,
-                                rank_by_signature=config.rank_by_signature,
-                                multiprobe_offsets=config.multiprobe_offsets,
-                                use_pallas=use_pallas, timer=timer,
-                                probe_stats=probe_stats)
-    ids = np.asarray(ids_j, np.int64)                     # (B, C)
-    valid = np.asarray(vals_j) > 0                        # (B, C)
-    empty = ~valid.any(axis=1)
-    if empty.any():            # degenerate rows: same fallback as sequential
-        ids[empty] = np.arange(c, dtype=np.int64)[None, :]
-        valid[empty] = True
+    ids, vals = batch_probe(queries, index, c,
+                            rank_by_signature=config.rank_by_signature,
+                            multiprobe_offsets=config.multiprobe_offsets,
+                            use_pallas=use_pallas, timer=timer,
+                            probe_stats=probe_stats)      # (B, C) each
+    valid = vals > 0
+    valid[~valid.any(axis=1)] = True      # fallback rows: every id counts
     n_hash = valid.sum(axis=1)                            # (B,)
 
     # -- stage 3: unified re-rank (cascade + backend-dispatched DTW) ------

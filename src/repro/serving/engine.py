@@ -50,6 +50,7 @@ from typing import List, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from repro.bench.timing import StageTimer
 from repro.core.dtw import BIG
 from repro.core.index import SSHIndex
 from repro.core.rerank import SearchStats
@@ -414,24 +415,26 @@ class ServingEngine:
     def search_batch(self, queries: jnp.ndarray) -> List[SearchResult]:
         """Serve a caller-assembled batch directly (no queue)."""
         queries = jnp.asarray(queries)
-        t0 = time.perf_counter()
-        with self._serve_lock:
-            self._drain_inserts()
-            res = self.searcher.search_batch(queries)
-        wall = time.perf_counter() - t0
         b = int(queries.shape[0])
-        self.metrics.set_index_bytes(self.index.nbytes())
-        self.metrics.on_batch(
-            b, [wall] * b, [0.0] * b,
-            list(res.pruned_by_hash_frac[:b]),
-            list(res.pruned_total_frac[:b]),
-            len(self._pending),
-            lb_pruned_frac=_lb_fracs(res),
-            dtw_abandoned_frac=_abandon_fracs(res),
-            stage_seconds=_stage_seconds(res),
-            sig_cache_hits=_sig_hits(res),
-            **_fleet_counters(res))
-        return [res.per_query(i) for i in range(b)]
+        with StageTimer.span("batch", size=b, bucket=b, head_wait_us=0):
+            t0 = time.perf_counter()
+            with self._serve_lock:
+                self._drain_inserts()
+                res = self.searcher.search_batch(queries)
+            wall = time.perf_counter() - t0
+            with StageTimer.span("engine.deliver"):
+                self.metrics.set_index_bytes(self.index.nbytes())
+                self.metrics.on_batch(
+                    b, [wall] * b, [0.0] * b,
+                    list(res.pruned_by_hash_frac[:b]),
+                    list(res.pruned_total_frac[:b]),
+                    len(self._pending),
+                    lb_pruned_frac=_lb_fracs(res),
+                    dtw_abandoned_frac=_abandon_fracs(res),
+                    stage_seconds=_stage_seconds(res),
+                    sig_cache_hits=_sig_hits(res),
+                    **_fleet_counters(res))
+                return [res.per_query(i) for i in range(b)]
 
     def flush_inserts(self) -> None:
         """Apply queued streaming inserts to the index *now*.
@@ -526,11 +529,14 @@ class ServingEngine:
                 return
             self.searcher.insert(series)
 
+    def _bucket(self, b: int) -> int:
+        """The compiled batch size a batch of ``b`` requests pads to."""
+        return next(s for s in self.config.buckets() if s >= b)
+
     def _pad_batch(self, queries: List[jnp.ndarray]) -> jnp.ndarray:
         """Pad to the next bucket size by repeating the first query."""
         b = len(queries)
-        bucket = next(s for s in self.config.buckets() if s >= b)
-        block = list(queries) + [queries[0]] * (bucket - b)
+        block = list(queries) + [queries[0]] * (self._bucket(b) - b)
         return jnp.stack(block, axis=0)
 
     def _collect(self, first: _Request,
@@ -581,27 +587,37 @@ class ServingEngine:
             else alpha * sample + (1.0 - alpha) * prev
 
     def _worker(self) -> None:
-        pol = self.config.batch_policy
         while True:
             with self._cond:
                 opened_idle = not self._pending
-                while not self._pending:
-                    self._cond.wait()
+                if not self._pending:
+                    with StageTimer.span("engine.wait"):
+                        while not self._pending:
+                            self._cond.wait()
                 item = self._pending.popleft()
             if item is self._STOP:
                 return
-            batch = self._collect(item, opened_idle)
+            with StageTimer.span("engine.collect"):
+                batch = self._collect(item, opened_idle)
             t0 = time.perf_counter()
-            try:                 # a failing insert also fails the batch
-                with self._serve_lock:       # loudly (and keeps the worker
-                    self._drain_inserts()    # alive for later requests)
-                    block = self._pad_batch([r.query for r in batch])
-                    res = self.searcher.search_batch(block)
-            except Exception as exc:
-                for r in batch:
-                    r.future.set_exception(exc)
-                continue
-            done = time.perf_counter()
+            with StageTimer.span(
+                    "batch", size=len(batch), bucket=self._bucket(len(batch)),
+                    head_wait_us=round((t0 - batch[0].t_enqueue) * 1e6)):
+                self._serve(batch, t0)
+
+    def _serve(self, batch: List[_Request], t0: float) -> None:
+        """Search one collected batch and resolve its futures."""
+        try:                     # a failing insert also fails the batch
+            with self._serve_lock:           # loudly (and keeps the worker
+                self._drain_inserts()        # alive for later requests)
+                block = self._pad_batch([r.query for r in batch])
+                res = self.searcher.search_batch(block)
+        except Exception as exc:
+            for r in batch:
+                r.future.set_exception(exc)
+            return
+        done = time.perf_counter()
+        with StageTimer.span("engine.deliver"):
             self._observe_service(res, done - t0)
             for i, r in enumerate(batch):
                 r.future.set_result(res.per_query(i))
@@ -618,5 +634,6 @@ class ServingEngine:
                 stage_seconds=_stage_seconds(res),
                 sig_cache_hits=_sig_hits(res),
                 batch_wait_s=t0 - batch[0].t_enqueue,
-                batch_occupancy=len(batch) / pol.max_batch,
+                batch_occupancy=(len(batch)
+                                 / self.config.batch_policy.max_batch),
                 **_fleet_counters(res))

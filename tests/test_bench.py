@@ -4,6 +4,7 @@ path (DESIGN.md §8).  All tests carry the ``bench`` marker (CI runs them
 as a dedicated job step)."""
 import os
 import sys
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -238,6 +239,35 @@ class TestStageTiming:
             assert sync("x") == "x"
         assert off.timings == {}
 
+    def test_spans_and_fetches_carry_their_stats(self, monkeypatch):
+        import jax
+        from repro.bench import to_host
+        seen = []
+
+        class Annotation:
+            def __init__(self, name, **stats):
+                seen.append((name, stats))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+        off = StageTimer(enabled=False)
+        with off.stage("dtw", pairs=5) as sync:
+            assert sync("x") == "x"
+        with StageTimer.span("pairs", pairs=2, union=1):
+            pass
+        host = to_host(np.arange(3))               # already on the host
+        dev = to_host(jnp.arange(4, dtype=jnp.float32))
+        assert isinstance(dev, np.ndarray) and dev.tolist() == [0, 1, 2, 3]
+        assert host.tolist() == [0, 1, 2]
+        assert seen == [("ssh.dtw", {"pairs": 5}),
+                        ("ssh.pairs", {"pairs": 2, "union": 1}),
+                        ("ssh.fetch", {"bytes": 16})]
+
     def test_sequential_all_stages_present_sum_le_total(self, db, index):
         res = ssh_search(db[3], index, config=self.CFG)
         assert res.stats.stage_seconds is not None
@@ -260,6 +290,71 @@ class TestStageTiming:
         assert off.stats.stage_seconds is None
         np.testing.assert_array_equal(on.ids, off.ids)
         np.testing.assert_allclose(on.dists, off.dists)
+
+    @pytest.mark.parametrize("timings", [False, True])
+    def test_served_batch_emits_spans_and_syncs_only_when_timed(
+            self, db, index, monkeypatch, timings):
+        """A batch served by the engine's worker opens every ``ssh.*``
+        span whether or not it is timed; untimed it never waits on the
+        device (the timed control does), and answers the same."""
+        import jax
+        from jax._src import array as jax_array
+        from repro.db import BatchPolicy
+        from repro.serving import ServingEngine
+        cfg = self.CFG.replace(searcher="batched", stage_timings=timings,
+                               batch_policy=BatchPolicy(max_batch=4,
+                                                        max_wait_ms=50.0))
+        engine = ServingEngine(index, cfg)
+        queries = [3, 9, 14]
+        want = engine.search_batch(db[jnp.asarray(queries)])   # compiles
+        names, syncs, batches = [], [], []
+
+        class Annotation:
+            def __init__(self, name, **stats):
+                names.append(name)
+                if name == "ssh.batch":
+                    batches.append(stats)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        real_sync = jax.block_until_ready
+        real_method = jax_array.ArrayImpl.block_until_ready
+
+        def sync(x):
+            syncs.append("jax.block_until_ready")
+            return real_sync(x)
+
+        def method(self):
+            syncs.append("Array.block_until_ready")
+            return real_method(self)
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+        monkeypatch.setattr(jax, "block_until_ready", sync)
+        monkeypatch.setattr(jax_array.ArrayImpl, "block_until_ready",
+                            method)
+        futs = [engine.submit(db[q]) for q in queries]
+        with engine:
+            got = [f.result(timeout=120) for f in futs]
+            # with the queue empty again the worker waits for a request
+            deadline = time.monotonic() + 60.0
+            while ("ssh.engine.wait" not in names
+                   and time.monotonic() < deadline):
+                time.sleep(0.01)
+        assert {"ssh.engine.collect", "ssh.batch", "ssh.encode",
+                "ssh.probe", "ssh.lb", "ssh.pairs", "ssh.lb_improved",
+                "ssh.dtw", "ssh.fetch", "ssh.engine.deliver",
+                "ssh.engine.wait"} <= set(names)
+        assert names.count("ssh.batch") == 1
+        assert batches[0]["size"] == 3 and batches[0]["bucket"] == 4
+        assert batches[0]["head_wait_us"] >= 0
+        assert (len(syncs) > 0) == timings
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(a.ids, b.ids)
+            np.testing.assert_array_equal(a.dists, b.dists)
 
     def test_engine_metrics_surface_stage_means(self, db, index):
         from repro.serving import ServingEngine
